@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
-import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -49,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.compileguard import CompileGuard
+from ..common import spans
 from .registry import unknown_name_message
 
 PyTree = Any
@@ -540,10 +540,16 @@ class AsyncRoundEngine:
 
     def run_flush(self, batch_fn: Callable[[int], Any],
                   weights) -> "RoundRecord":
+        r = len(self.server.history)
+        with spans.round_span(r) as rspan:
+            rec = self._flush(r, rspan, batch_fn, weights)
+        self.server.history.append(rec)
+        return rec
+
+    def _flush(self, r: int, rspan, batch_fn: Callable[[int], Any],
+               weights) -> "RoundRecord":
         from .server import RoundRecord
         server = self.server
-        t0 = time.perf_counter()
-        r = len(server.history)
         w = jnp.asarray(weights, jnp.float32)
         for hook in server.hooks:
             new_w = hook.on_round_start(server, r, w)
@@ -600,8 +606,7 @@ class AsyncRoundEngine:
         s = stats["staleness"]
         eff = stats["effective_weights"]
         rec = RoundRecord(
-            r, float(stats["losses"].mean()), ev,
-            time.perf_counter() - t0, 0.0, 0.0,
+            r, float(stats["losses"].mean()), ev, rspan.elapsed(), 0.0, 0.0,
             # like the sync loop: dropped (weight-0) entries aggregate
             # nothing and don't count as participants
             n_participants=int(np.unique(
@@ -620,8 +625,7 @@ class AsyncRoundEngine:
         self._wasted = 0.0     # billed to this flush's record
         for hook in server.hooks:
             hook.on_round_end(server, rec, metrics)
-        rec.seconds = time.perf_counter() - t0
-        server.history.append(rec)
+        rec.seconds = rspan.close()
         return rec
 
     def _codec_decay(self) -> np.ndarray:

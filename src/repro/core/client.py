@@ -41,9 +41,11 @@ def local_update(loss_fn: Callable, global_params: PyTree, mask: PyTree,
     """One client's round.  ``batches`` leaves have leading (steps,) dim.
 
     Returns (delta, metrics) where delta = trained - global (exact zeros
-    on frozen units).  With ``norm_hook``, metrics additionally carries
-    ``unit_sqnorm`` — (U,) per-unit squared gradient norms summed over
-    the local steps (frozen units: exact zeros).
+    on frozen units) and metrics hold the loss of every local step
+    (``loss_steps``, (steps,)), their mean and the last.  With
+    ``norm_hook``, metrics additionally carries ``unit_sqnorm`` — (U,)
+    per-unit squared gradient norms summed over the local steps (frozen
+    units: exact zeros).
     """
     loss_kwargs = loss_kwargs or {}
     opt_init, opt_step = ((adam_init, adam_step) if optimizer == "adam"
@@ -83,7 +85,8 @@ def local_update(loss_fn: Callable, global_params: PyTree, mask: PyTree,
         init = init + (jnp.zeros((norm_hook.n_units,), jnp.float32),)
     carry, losses = jax.lax.scan(step, init, batches)
     delta = pt.tree_sub(carry[0], global_params)
-    metrics = {"loss_mean": losses.mean(), "loss_last": losses[-1]}
+    metrics = {"loss_mean": losses.mean(), "loss_last": losses[-1],
+               "loss_steps": losses}
     if norm_hook is not None:
         metrics["unit_sqnorm"] = carry[2]
     return delta, metrics
@@ -183,7 +186,8 @@ def local_update_packed(loss_fn: Callable, global_params: PyTree,
         init = init + (jnp.zeros((norm_hook.n_units,), jnp.float32),)
     carry, losses = jax.lax.scan(step, init, batches)
     delta = pt.tree_sub(carry[0], packed0)
-    metrics = {"loss_mean": losses.mean(), "loss_last": losses[-1]}
+    metrics = {"loss_mean": losses.mean(), "loss_last": losses[-1],
+               "loss_steps": losses}
     if norm_hook is not None:
         metrics["unit_sqnorm"] = carry[2]
     return delta, metrics

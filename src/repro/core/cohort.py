@@ -44,7 +44,6 @@ logging) composes unchanged.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, ClassVar, Dict, List, Optional, Tuple, \
     Type, Union
 
@@ -53,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.compileguard import CompileGuard
+from ..common import spans
 from .registry import unknown_name_message
 
 PyTree = Any
@@ -183,8 +183,8 @@ def _uniform_draw(key, n_registered: int, cohort: int) -> np.ndarray:
     """
     if cohort >= n_registered:
         return np.arange(n_registered, dtype=np.int32)
-    words = [int(w) for w in np.asarray(jax.random.key_data(key),
-                                        np.uint32).ravel()]
+    words = [int(w) for w in np.asarray(
+        spans.fetch(jax.random.key_data(key), "sample"), np.uint32).ravel()]
     rng = np.random.Generator(np.random.Philox(
         np.random.SeedSequence(words + [0xF107D])))
     # vectorized pre-draw: t_j ~ U[0, j] for j = R-C .. R-1
@@ -209,7 +209,8 @@ def _scored_draw(key, signal: np.ndarray, seen: np.ndarray,
         return _uniform_draw(key, signal.shape[0], cohort)
     s = np.where(seen, signal, signal[seen].max()).astype(np.float64)
     z = (s - s.mean()) / (s.std() + 1e-6)
-    g = np.asarray(jax.random.gumbel(key, s.shape), np.float64)
+    g = np.asarray(spans.fetch(jax.random.gumbel(key, s.shape), "sample"),
+                   np.float64)
     top = np.argsort(-(z + g), kind="stable")[:cohort]
     return np.sort(top).astype(np.int32)
 
@@ -313,11 +314,16 @@ def build_cohort_programs(loss_fn: Callable, assign, fl,
     cohort = packed_cohort_fn(loss_fn, assign, fl, loss_kwargs,
                               scoring=scoring)
 
+    # each stage under its scope (fl/<stage>), as in the sync round step
+    @jax.named_scope("fl/select")
     def select(key, sel_state=None):
         sel = strat.select(key, _live_ctx(ctx, sel_state))
         if fl.always_train_head:
             sel = sel.at[:, -1].set(1.0)
         return sel
+
+    # the zero partial aggregate (the program keeps its function's name)
+    acc_init = jax.named_scope("fl/aggregate")(acc_init)
 
     inject_on = _faults.delta_faults_configured(fl)
     gate_on = _faults.gate_enabled(fl)
@@ -326,12 +332,15 @@ def build_cohort_programs(loss_fn: Callable, assign, fl,
 
     def chunk_step(global_params, acc, sel_chunk, w_chunk, positions,
                    batches, mode=None, scale=None, codec_key=None):
-        rows, valid = jax.vmap(
-            lambda s: slot_plan(assign, s, n_slots, global_params)
-        )(sel_chunk)
-        pdeltas, metrics = run_cohort(cohort, global_params, rows, valid,
-                                      batches)
-        out = {"loss": metrics["loss_mean"]}
+        with jax.named_scope("fl/slot_plan"):
+            rows, valid = jax.vmap(
+                lambda s: slot_plan(assign, s, n_slots, global_params)
+            )(sel_chunk)
+        with jax.named_scope("fl/local_train"):
+            pdeltas, metrics = run_cohort(cohort, global_params, rows,
+                                          valid, batches)
+        out = {"loss": metrics["loss_mean"],
+               "loss_steps": metrics["loss_steps"]}
         if scoring:
             out["unit_sqnorm"] = metrics["unit_sqnorm"]
         # uplink codec (DESIGN.md §16): encode/decode compiles into the
@@ -340,24 +349,29 @@ def build_cohort_programs(loss_fn: Callable, assign, fl,
         # stateless codecs reach this path — FLConfig rejects the
         # error-feedback codec × cohort engine combination up front.
         if codec_fn is not None:
-            pdeltas, _ = codec_fn(pdeltas, rows, valid, w_chunk,
-                                  codec_key)
+            with jax.named_scope("fl/codec"):
+                pdeltas, _ = codec_fn(pdeltas, rows, valid, w_chunk,
+                                      codec_key)
         # fault axis (DESIGN.md §14): corruption + validation gate ride
         # the chunk program when configured — both bitwise identities
         # when untripped, so zero-rate chaos keeps chunked == single-
         # shot == plain bitwise
         if inject_on:
-            if mode is None:
-                mode = jnp.zeros((chunk_width,), jnp.int32)
-                scale = jnp.ones((chunk_width,), jnp.float32)
-            pdeltas = _faults.chaos_inject(pdeltas, mode, scale)
+            with jax.named_scope("fl/faults"):
+                if mode is None:
+                    mode = jnp.zeros((chunk_width,), jnp.int32)
+                    scale = jnp.ones((chunk_width,), jnp.float32)
+                pdeltas = _faults.chaos_inject(pdeltas, mode, scale)
         if gate_on:
-            pdeltas, w_chunk, quar = gate_packed_updates(
-                assign, pdeltas, valid, w_chunk, fl.max_delta_norm)
+            with jax.named_scope("fl/gate"):
+                pdeltas, w_chunk, quar = gate_packed_updates(
+                    assign, pdeltas, valid, w_chunk, fl.max_delta_norm)
             out["quarantined"] = quar
-        acc = accumulate(acc, pdeltas, rows, valid, w_chunk, positions)
+        with jax.named_scope("fl/aggregate"):
+            acc = accumulate(acc, pdeltas, rows, valid, w_chunk, positions)
         return acc, out
 
+    @jax.named_scope("fl/finalize")
     def finalize(global_params, acc, sel, weights, losses):
         new_params = finalize_agg(global_params, acc, sel, weights)
         # same jnp.mean over the same (C,) values the sync round step
@@ -446,7 +460,16 @@ class CohortEngine:
                 "a cohort round is already in flight; stream its "
                 "remaining chunks and finish_round() first")
         r = len(server.history)
-        t0 = time.perf_counter()
+        rspan = spans.round_span(r)
+        try:
+            self._partial = self._begin(r, rspan, weights)
+        except BaseException:
+            rspan.close()
+            raise
+        return self._partial
+
+    def _begin(self, r: int, rspan, weights) -> Dict[str, Any]:
+        server = self.server
         # SAME key-stream slot as Server.run_round: round key first,
         # then hooks (StragglerDropout) draw — bitwise-equal streams
         rk = server.next_key()
@@ -480,24 +503,26 @@ class CohortEngine:
             mask = np.ones((c,), np.float32)
             mask[dead] = 0.0
             w = w * jnp.asarray(mask)
-        for hook in server.hooks:
-            new_w = hook.on_round_start(server, r, w)
-            if new_w is not None:
-                w = new_w
-        w_np = np.asarray(w, np.float32)
+        with spans.span("fl.hooks", phase="start"):
+            for hook in server.hooks:
+                new_w = hook.on_round_start(server, r, w)
+                if new_w is not None:
+                    w = new_w
+        w_np = np.asarray(spans.fetch(w, "weights"), np.float32)
         n_part = int(np.count_nonzero(w_np))
         p: Dict[str, Any] = {
-            "round": r, "t0": t0, "ids": ids, "w": jnp.asarray(w_np),
+            "round": r, "span": rspan, "ids": ids, "w": jnp.asarray(w_np),
             "eff_w": [float(x) for x in w_np], "n_part": n_part,
-            "chunk": 0, "losses": [], "sqnorms": [], "quars": [],
-            "skipped": n_part == 0, "sel": None, "acc": None,
+            "chunk": 0, "losses": [], "loss_steps": [], "sqnorms": [],
+            "quars": [], "skipped": n_part == 0, "sel": None, "acc": None,
         }
         if n_part:
             st = server.sel_state
-            sel = self.programs.select(rk) if st is None \
-                else self.programs.select(rk, st)
+            sel = spans.dispatch(self.programs.select, rk) if st is None \
+                else spans.dispatch(self.programs.select, rk, st)
             p["sel"] = sel
-            p["acc"] = self.programs.acc_init(server.global_params())
+            p["acc"] = spans.dispatch(self.programs.acc_init,
+                                      server.global_params())
             if getattr(self.fl, "client_shards", 0):
                 # the sharded chunk program commits its acc output to
                 # the (client,) mesh; the fresh accumulator must start
@@ -508,7 +533,6 @@ class CohortEngine:
                     p["acc"],
                     NamedSharding(make_client_mesh(self.fl.client_shards),
                                   PartitionSpec()))
-        self._partial = p
         return p
 
     def _resample_crashed(self, r: int,
@@ -562,6 +586,11 @@ class CohortEngine:
             raise RuntimeError(
                 f"all {self.n_chunks} chunks of round {p['round']} are "
                 "already streamed; finish_round()")
+        with spans.span("fl.chunk", chunk=j):
+            self._step_chunk(p, j, batch_fn)
+
+    def _step_chunk(self, p: Dict[str, Any], j: int,
+                    batch_fn: Callable[[int, np.ndarray], Any]) -> None:
         lo, hi = j * self.chunk, (j + 1) * self.chunk
         pos = np.arange(lo, hi)
         batches = batch_fn(p["round"], p["ids"][pos])
@@ -576,44 +605,53 @@ class CohortEngine:
         if getattr(self.fl, "codec", "none") != "none":
             chunk_kw["codec_key"] = jax.random.fold_in(
                 jax.random.fold_in(self._codec_base, p["round"]), j)
-        acc, mets = self.programs.chunk(
-            self.server.global_params(), p["acc"], p["sel"][lo:hi],
-            p["w"][lo:hi], jnp.asarray(pos, jnp.int32), batches,
-            **chunk_kw)
+        acc, mets = spans.dispatch(
+            self.programs.chunk, self.server.global_params(), p["acc"],
+            p["sel"][lo:hi], p["w"][lo:hi], jnp.asarray(pos, jnp.int32),
+            batches, **chunk_kw)
         p["acc"] = acc
-        p["losses"].append(np.asarray(mets["loss"], np.float32))
+        p["losses"].append(np.asarray(spans.fetch(mets["loss"], "loss"),
+                                      np.float32))
+        # kept on the device: nothing on the host reads it per chunk
+        p["loss_steps"].append(mets["loss_steps"])
         if "unit_sqnorm" in mets:
-            p["sqnorms"].append(np.asarray(mets["unit_sqnorm"],
-                                           np.float32))
+            p["sqnorms"].append(np.asarray(
+                spans.fetch(mets["unit_sqnorm"], "sqnorm"), np.float32))
         if "quarantined" in mets:
-            p["quars"].append(np.asarray(mets["quarantined"], np.float32))
+            p["quars"].append(np.asarray(
+                spans.fetch(mets["quarantined"], "quarantined"), np.float32))
         p["chunk"] = j + 1
 
     def finish_round(self):
-        from .server import RoundRecord
         p = self._partial
         if p is None:
             raise RuntimeError("no cohort round in flight; begin_round "
                                "first")
+        if not p["skipped"] and p["chunk"] != self.n_chunks:
+            raise RuntimeError(
+                f"round {p['round']} has streamed {p['chunk']}/"
+                f"{self.n_chunks} chunks; step_chunk the rest first")
+        with p["span"] as rspan:
+            rec = self._finish(p, rspan)
+        self.server.history.append(rec)
+        self.server._trim_history()
+        return rec
+
+    def _finish(self, p: Dict[str, Any], rspan):
+        from .server import RoundRecord
         server = self.server
         r = p["round"]
         c = self.fl.n_clients
-        t0 = p["t0"]
         if p["skipped"]:
             # loss 0.0 (NOT NaN): a skipped round must never leak NaN
             # into loss summaries / EMA consumers downstream
-            rec = RoundRecord(r, 0.0, None,
-                              time.perf_counter() - t0, 0.0, 0.0,
+            rec = RoundRecord(r, 0.0, None, rspan.elapsed(), 0.0, 0.0,
                               n_participants=0, skipped=True,
                               dropped=True, effective_weights=p["eff_w"])
             server.sel_history.append(
                 np.zeros((c, self.assign.n_units), np.float32))
             metrics = None
         else:
-            if p["chunk"] != self.n_chunks:
-                raise RuntimeError(
-                    f"round {r} has streamed {p['chunk']}/"
-                    f"{self.n_chunks} chunks; step_chunk the rest first")
             losses = jnp.concatenate(
                 [jnp.asarray(x) for x in p["losses"]]) \
                 if len(p["losses"]) > 1 else jnp.asarray(p["losses"][0])
@@ -624,13 +662,16 @@ class CohortEngine:
                 # quarantined clients already accumulated with weight 0
                 # per chunk; zero them in the full-cohort denominator too
                 w_fin = w_fin * jnp.asarray(1.0 - quar_full)
-            new_params, loss_mean = self.programs.finalize(
-                server.global_params(), p["acc"], p["sel"], w_fin,
-                losses)
+            new_params, loss_mean = spans.dispatch(
+                self.programs.finalize, server.global_params(), p["acc"],
+                p["sel"], w_fin, losses)
             server.params = new_params   # star topologies: state==params
-            server.sel_history.append(np.asarray(p["sel"]))
+            server.sel_history.append(spans.fetch(p["sel"], "sel"))
             metrics = {"loss_mean": loss_mean, "loss_per_client": losses,
                        "sel": p["sel"]}
+            # every chunk's, unless a restore began the round midway
+            if len(p["loss_steps"]) == self.n_chunks:
+                metrics["loss_steps"] = jnp.concatenate(p["loss_steps"])
             if p["sqnorms"]:
                 metrics["unit_sqnorm"] = np.concatenate(p["sqnorms"],
                                                         axis=0)
@@ -638,26 +679,26 @@ class CohortEngine:
                 metrics["quarantined"] = quar_full
             ev = None
             if server.eval_fn is not None:
-                ev = float(server.eval_fn(server.global_params()))
-            rec = RoundRecord(r, float(loss_mean), ev,
-                              time.perf_counter() - t0, 0.0, 0.0,
+                ev = float(spans.fetch(server.eval_fn(server.global_params()),
+                                       "eval"))
+            rec = RoundRecord(r, float(spans.fetch(loss_mean, "loss")), ev,
+                              rspan.elapsed(), 0.0, 0.0,
                               n_participants=p["n_part"],
                               effective_weights=p["eff_w"])
-        # selection-state telemetry BEFORE end-of-round hooks, exactly
-        # like the sync loop (a Checkpointer hook must save post-round
-        # state for bit-exact mid-fit resume)
-        server.update_sel_state(server._round_telemetry(r, metrics,
-                                                        p["eff_w"]))
-        self._update_fleet(p, metrics)
-        # clear the in-flight round BEFORE end hooks: a ChaosHook kill
-        # must not leave a completed round marked partial, or the resumed
-        # run would double-apply it
-        self._partial = None
-        for hook in server.hooks:
-            hook.on_round_end(server, rec, metrics)
-        rec.seconds = time.perf_counter() - t0
-        server.history.append(rec)
-        server._trim_history()
+        with spans.span("fl.hooks", phase="end"):
+            # selection-state telemetry BEFORE end-of-round hooks,
+            # exactly like the sync loop (a Checkpointer hook must save
+            # post-round state for bit-exact mid-fit resume)
+            server.update_sel_state(server._round_telemetry(r, metrics,
+                                                            p["eff_w"]))
+            self._update_fleet(p, metrics)
+            # clear the in-flight round BEFORE end hooks: a ChaosHook kill
+            # must not leave a completed round marked partial, or the
+            # resumed run would double-apply it
+            self._partial = None
+            for hook in server.hooks:
+                hook.on_round_end(server, rec, metrics)
+        rec.seconds = rspan.close()
         return rec
 
     def _update_fleet(self, p: Dict[str, Any],
@@ -678,7 +719,8 @@ class CohortEngine:
             if act.size:
                 e = self.fl.sampler_ema
                 seen = f.counts[act] > 0
-                loss = np.asarray(metrics["loss_per_client"],
+                loss = np.asarray(spans.fetch(metrics["loss_per_client"],
+                                              "loss_per_client"),
                                   np.float32)[active]
                 f.loss_ema[act] = np.where(
                     seen, e * f.loss_ema[act] + (1.0 - e) * loss, loss)
@@ -810,13 +852,14 @@ class CohortEngine:
             return
         pa = arrays["partial"]
         p: Dict[str, Any] = {
-            "round": int(pm["round"]), "t0": time.perf_counter(),
+            "round": int(pm["round"]),
+            "span": spans.round_span(int(pm["round"])),
             "ids": np.asarray(pa["ids"], np.int32),
             "w": jnp.asarray(np.asarray(pa["w"], np.float32)),
             "eff_w": [float(x) for x in pm["eff_w"]],
             "n_part": int(pm["n_part"]), "chunk": int(pm["chunk"]),
             "skipped": bool(pm["skipped"]),
-            "losses": [], "sqnorms": [], "quars": [],
+            "losses": [], "loss_steps": [], "sqnorms": [], "quars": [],
             "sel": None, "acc": None,
         }
         if not p["skipped"]:

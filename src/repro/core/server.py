@@ -20,7 +20,6 @@ the global params are untouched and the ``RoundRecord`` carries
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import jax
@@ -28,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.compileguard import CompileGuard
+from ..common import spans
 from . import codecs, comm
 from .federation import FLConfig
 from .masking import UnitAssignment
@@ -164,7 +164,7 @@ class CommAccounting(ServerHook):
         if quar is not None:
             # quarantined clients uploaded (billed above) but their
             # deltas were discarded by the validation gate
-            qm = (np.asarray(quar) > 0).astype(sel.dtype)
+            qm = (spans.fetch(quar, "quarantined") > 0).astype(sel.dtype)
             record.wasted_bytes = server.topology.round_bytes(
                 sel * qm[:, None], ub, server.fl)["uplink"]
 
@@ -363,6 +363,12 @@ class Server:
 
     def run_round(self, client_batches, weights=None) -> RoundRecord:
         """client_batches: pytree with (C, steps, ...) leaves."""
+        return self._run_round(lambda: client_batches, weights)
+
+    def _run_round(self, load: Callable[[], Any],
+                   weights=None) -> RoundRecord:
+        """One round; ``load()`` gives its client batches (called first,
+        inside the round's span)."""
         if self.async_engine is not None:
             raise RuntimeError(
                 "server is in buffered-async mode (FLConfig.async_buffer "
@@ -373,25 +379,33 @@ class Server:
                 "server is in cohort-engine mode (FLConfig.n_registered/"
                 "cohort_chunk); a plain round would desync the engine's "
                 "fleet/key bookkeeping — use run()/Federation.fit")
-        t0 = time.perf_counter()
         r = len(self.history)
+        with spans.round_span(r) as rspan:
+            rec = self._round(r, rspan, load(), weights)
+        self.history.append(rec)
+        self._trim_history()
+        return rec
+
+    def _round(self, r: int, rspan, client_batches,
+               weights) -> RoundRecord:
         rk = self.next_key()
         c = self.fl.n_clients
         if weights is None:
             weights = jnp.ones((c,), jnp.float32)
-        for hook in self.hooks:
-            new_w = hook.on_round_start(self, r, weights)
-            if new_w is not None:
-                weights = new_w
-        n_part = int(np.count_nonzero(np.asarray(weights)))
-        eff_w = [float(x) for x in np.asarray(weights)]
+        with spans.span("fl.hooks", phase="start"):
+            for hook in self.hooks:
+                new_w = hook.on_round_start(self, r, weights)
+                if new_w is not None:
+                    weights = new_w
+        w_host = spans.fetch(weights, "weights")
+        n_part = int(np.count_nonzero(w_host))
+        eff_w = [float(x) for x in w_host]
         if n_part == 0:
             # every client dropped: a FedAvg denominator of zero — the
             # round is a recorded no-op, global params unchanged.  The
             # record carries loss 0.0 + dropped=True (not NaN: a NaN
             # here used to leak into logs and loss EMAs)
-            rec = RoundRecord(r, 0.0, None,
-                              time.perf_counter() - t0, 0.0, 0.0,
+            rec = RoundRecord(r, 0.0, None, rspan.elapsed(), 0.0, 0.0,
                               n_participants=0, skipped=True,
                               dropped=True, effective_weights=eff_w)
             self.sel_history.append(
@@ -410,31 +424,33 @@ class Server:
                 # step; the new residual rides the metrics back out
                 step_kw["codec_state"] = self.codec_state
             if self.sel_state is not None:
-                self.params, metrics = self.round_step(
-                    self.params, client_batches, weights, rk,
-                    self.sel_state, **step_kw)
+                self.params, metrics = spans.dispatch(
+                    self.round_step, self.params, client_batches, weights,
+                    rk, self.sel_state, **step_kw)
             else:
-                self.params, metrics = self.round_step(
-                    self.params, client_batches, weights, rk, **step_kw)
+                self.params, metrics = spans.dispatch(
+                    self.round_step, self.params, client_batches, weights,
+                    rk, **step_kw)
             if "codec_state" in metrics:
                 self.codec_state = metrics.pop("codec_state")
-            self.sel_history.append(np.asarray(metrics["sel"]))
+            self.sel_history.append(spans.fetch(metrics["sel"], "sel"))
             ev = None
             if self.eval_fn is not None:
-                ev = float(self.eval_fn(self.global_params()))
-            rec = RoundRecord(r, float(metrics["loss_mean"]), ev,
-                              time.perf_counter() - t0, 0.0, 0.0,
+                ev = float(spans.fetch(self.eval_fn(self.global_params()),
+                                       "eval"))
+            rec = RoundRecord(r, float(spans.fetch(metrics["loss_mean"],
+                                                   "loss")), ev,
+                              rspan.elapsed(), 0.0, 0.0,
                               n_participants=n_part,
                               effective_weights=eff_w)
-        # fold the round's norm telemetry into the selection state
-        # BEFORE the end-of-round hooks run, so a Checkpointer hook
-        # saves the post-round state (bit-exact mid-fit resume)
-        self.update_sel_state(self._round_telemetry(r, metrics, eff_w))
-        for hook in self.hooks:
-            hook.on_round_end(self, rec, metrics)
-        rec.seconds = time.perf_counter() - t0
-        self.history.append(rec)
-        self._trim_history()
+        with spans.span("fl.hooks", phase="end"):
+            # fold the round's norm telemetry into the selection state
+            # BEFORE the end-of-round hooks run, so a Checkpointer hook
+            # saves the post-round state (bit-exact mid-fit resume)
+            self.update_sel_state(self._round_telemetry(r, metrics, eff_w))
+            for hook in self.hooks:
+                hook.on_round_end(self, rec, metrics)
+        rec.seconds = rspan.close()
         return rec
 
     def _trim_history(self) -> None:
@@ -477,7 +493,8 @@ class Server:
                 or round_idx % self.fl.score_every != 0:
             return None
         active = (np.asarray(eff_w, np.float32) > 0).astype(np.float32)
-        sq = np.asarray(metrics["unit_sqnorm"], np.float32)
+        sq = np.asarray(spans.fetch(metrics["unit_sqnorm"], "sqnorm"),
+                        np.float32)
         sel = np.asarray(metrics["sel"], np.float32)
         counts = (sel * active[:, None]).sum(0)
         # synchronous participants all carry weight 1, so the weighted
@@ -528,7 +545,7 @@ class Server:
         self.hooks.extend(extra)
         try:
             for r in range(rounds):
-                self.run_round(batch_fn(r), weights)
+                self._run_round(lambda: batch_fn(r), weights)
         finally:
             for h in extra:
                 self.hooks.remove(h)

@@ -56,6 +56,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 
+from ..common import spans
 from ..data import FederatedLoader
 from .federation import FLConfig, build_round_step
 from .masking import UnitAssignment, build_units_flat, build_units_zoo
@@ -232,15 +233,23 @@ class Federation:
             # engine indexes it by absolute round (resume-safe), so no
             # history base is added here
             return self.server.run(
-                rounds, lambda r, ids: jax.tree_util.tree_map(
-                    jnp.asarray, self.loader.client_batches(r, ids)),
+                rounds, lambda r, ids: self._feed(self.loader.client_batches,
+                                                  r, ids),
                 weights=weights, log_every=log_every)
         base = 0 if self.server.async_engine is not None \
             else len(self.server.history)
         return self.server.run(
-            rounds, lambda r: jax.tree_util.tree_map(
-                jnp.asarray, self.loader.round_batches(base + r)),
+            rounds, lambda r: self._feed(self.loader.round_batches, base + r),
             weights=weights, log_every=log_every)
+
+    @staticmethod
+    def _feed(build: Callable, *args) -> PyTree:
+        """``build(*args)``'s host batches, copied to the device."""
+        with spans.span("fl.feed"):
+            with spans.span("fl.feed.build"):
+                host = build(*args)
+            with spans.span("fl.feed.h2d"):
+                return jax.tree_util.tree_map(jnp.asarray, host)
 
     def run_round(self, client_batches, weights=None) -> RoundRecord:
         return self.server.run_round(client_batches, weights)

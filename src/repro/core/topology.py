@@ -137,9 +137,11 @@ def _cohort_runner(fl, width: int) -> Callable:
     def run(fn, gp, *per_client):
         out = shard_over_clients(fn, shards, width)(gp, *per_client)
         # what follows (FedAvg, the mean loss) sees the whole cohort on
-        # every device, so it sums in the order one device would
+        # every device, so it sums in the order one device would; the
+        # gather that brings it there is the aggregation's
         whole = NamedSharding(make_client_mesh(shards), P())
-        return jax.lax.with_sharding_constraint(out, whole)
+        with jax.named_scope("fl/aggregate"):
+            return jax.lax.with_sharding_constraint(out, whole)
 
     return run
 
@@ -228,10 +230,12 @@ def _star_round_step(loss_fn: Callable, assign: UnitAssignment, fl,
 
     def round_step(global_params, client_batches, weights, round_key,
                    sel_state=None, fault_plan=None, codec_state=None):
-        c = _live_ctx(ctx, sel_state)
-        sel = strat.select(round_key, c)
-        if fl.always_train_head:
-            sel = sel.at[:, -1].set(1.0)
+        # each stage under its scope (fl/<stage>): the op metadata that
+        # names the stage of every device operation in a trace
+        with jax.named_scope("fl/select"):
+            sel = strat.select(round_key, _live_ctx(ctx, sel_state))
+            if fl.always_train_head:
+                sel = sel.at[:, -1].set(1.0)
 
         quarantined = None
         new_codec_state = None
@@ -239,40 +243,57 @@ def _star_round_step(loss_fn: Callable, assign: UnitAssignment, fl,
             # every unit trained: unmasked local step + the topology's
             # dense aggregation — for hub, bit-exact with the
             # conventional-FedAvg baseline trace
-            deltas, metrics = run_cohort(dense_cohort, global_params,
-                                         client_batches)
-            new_params = aggregate_dense(global_params, deltas, sel, weights)
+            with jax.named_scope("fl/local_train"):
+                deltas, metrics = run_cohort(dense_cohort, global_params,
+                                             client_batches)
+            with jax.named_scope("fl/aggregate"):
+                new_params = aggregate_dense(global_params, deltas, sel,
+                                             weights)
         elif use_packed:
-            rows, valid = jax.vmap(
-                lambda s: slot_plan(assign, s, n_slots, global_params))(sel)
-            pdeltas, metrics = run_cohort(packed_cohort, global_params,
-                                          rows, valid, client_batches)
+            with jax.named_scope("fl/slot_plan"):
+                rows, valid = jax.vmap(
+                    lambda s: slot_plan(assign, s, n_slots,
+                                        global_params))(sel)
+            with jax.named_scope("fl/local_train"):
+                pdeltas, metrics = run_cohort(packed_cohort, global_params,
+                                              rows, valid, client_batches)
             if codec_fn is not None:
-                ck = jax.random.fold_in(round_key, _codecs.CODEC_KEY_TAG)
-                decay = jnp.ones((fl.n_clients,), jnp.float32)
-                pdeltas, new_codec_state = codec_fn(
-                    pdeltas, rows, valid, weights, ck, codec_state, decay)
+                with jax.named_scope("fl/codec"):
+                    ck = jax.random.fold_in(round_key,
+                                            _codecs.CODEC_KEY_TAG)
+                    decay = jnp.ones((fl.n_clients,), jnp.float32)
+                    pdeltas, new_codec_state = codec_fn(
+                        pdeltas, rows, valid, weights, ck, codec_state,
+                        decay)
             if inject_on:
-                if fault_plan is None:
-                    fault_plan = {
-                        "mode": jnp.zeros((fl.n_clients,), jnp.int32),
-                        "scale": jnp.ones((fl.n_clients,), jnp.float32)}
-                pdeltas = _faults.chaos_inject(pdeltas, fault_plan["mode"],
-                                               fault_plan["scale"])
+                with jax.named_scope("fl/faults"):
+                    if fault_plan is None:
+                        fault_plan = {
+                            "mode": jnp.zeros((fl.n_clients,), jnp.int32),
+                            "scale": jnp.ones((fl.n_clients,),
+                                              jnp.float32)}
+                    pdeltas = _faults.chaos_inject(
+                        pdeltas, fault_plan["mode"], fault_plan["scale"])
             if gate_on:
-                pdeltas, weights, quarantined = gate_packed_updates(
-                    assign, pdeltas, valid, weights, fl.max_delta_norm)
-            new_params = aggregate_packed(global_params, pdeltas, rows,
-                                          valid, sel, weights)
+                with jax.named_scope("fl/gate"):
+                    pdeltas, weights, quarantined = gate_packed_updates(
+                        assign, pdeltas, valid, weights, fl.max_delta_norm)
+            with jax.named_scope("fl/aggregate"):
+                new_params = aggregate_packed(global_params, pdeltas, rows,
+                                              valid, sel, weights)
         else:
-            deltas, metrics = run_cohort(masked_cohort, global_params,
-                                         sel, client_batches)
-            new_params = aggregate(global_params, deltas, sel, weights)
-        out_metrics = {
-            "loss_mean": metrics["loss_mean"].mean(),
-            "loss_per_client": metrics["loss_mean"],
-            "sel": sel,
-        }
+            with jax.named_scope("fl/local_train"):
+                deltas, metrics = run_cohort(masked_cohort, global_params,
+                                             sel, client_batches)
+            with jax.named_scope("fl/aggregate"):
+                new_params = aggregate(global_params, deltas, sel, weights)
+        with jax.named_scope("fl/finalize"):
+            out_metrics = {
+                "loss_mean": metrics["loss_mean"].mean(),
+                "loss_per_client": metrics["loss_mean"],
+                "loss_steps": metrics["loss_steps"],
+                "sel": sel,
+            }
         if scoring:
             out_metrics["unit_sqnorm"] = metrics["unit_sqnorm"]
         if quarantined is not None:
@@ -677,9 +698,10 @@ class Gossip(Topology):
 
         def round_step(state, client_batches, weights, round_key,
                        sel_state=None):
-            sel = strat.select(round_key, _live_ctx(ctx, sel_state))
-            if fl.always_train_head:
-                sel = sel.at[:, -1].set(1.0)
+            with jax.named_scope("fl/select"):
+                sel = strat.select(round_key, _live_ctx(ctx, sel_state))
+                if fl.always_train_head:
+                    sel = sel.at[:, -1].set(1.0)
             active = (weights > 0).astype(jnp.float32)       # (C,)
             hook = dense_norm_hook(assign) if scoring else None
 
@@ -691,22 +713,26 @@ class Gossip(Topology):
                                     loss_kwargs=loss_kwargs,
                                     norm_hook=hook)
 
-            deltas, metrics = jax.vmap(one_client)(state, sel,
-                                                   client_batches)
-            trained = jax.tree_util.tree_map(
-                lambda x, d: x + (d * active.reshape(
-                    (-1,) + (1,) * (d.ndim - 1))).astype(x.dtype),
-                state, deltas)
-            mixed = jax.tree_util.tree_map(
-                lambda x: jnp.tensordot(
-                    mix, x.astype(jnp.float32), axes=(1, 0),
-                    precision=F32).astype(x.dtype),
-                trained)
-            out_metrics = {
-                "loss_mean": metrics["loss_mean"].mean(),
-                "loss_per_client": metrics["loss_mean"],
-                "sel": sel,
-            }
+            with jax.named_scope("fl/local_train"):
+                deltas, metrics = jax.vmap(one_client)(state, sel,
+                                                       client_batches)
+            with jax.named_scope("fl/aggregate"):
+                trained = jax.tree_util.tree_map(
+                    lambda x, d: x + (d * active.reshape(
+                        (-1,) + (1,) * (d.ndim - 1))).astype(x.dtype),
+                    state, deltas)
+                mixed = jax.tree_util.tree_map(
+                    lambda x: jnp.tensordot(
+                        mix, x.astype(jnp.float32), axes=(1, 0),
+                        precision=F32).astype(x.dtype),
+                    trained)
+            with jax.named_scope("fl/finalize"):
+                out_metrics = {
+                    "loss_mean": metrics["loss_mean"].mean(),
+                    "loss_per_client": metrics["loss_mean"],
+                    "loss_steps": metrics["loss_steps"],
+                    "sel": sel,
+                }
             if scoring:
                 out_metrics["unit_sqnorm"] = metrics["unit_sqnorm"]
             return mixed, out_metrics

@@ -110,6 +110,7 @@ def quantize_pack(x, u, bits, *, interpret=None):
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct((n, cb), q_dtype),
         interpret=interpret,
+        name="quantize_pack",
     )(*[tiles(a) for a in streams], inv_t)
     packed = packed[:r * per].reshape(r, per * cb)[:, :w]
     return packed, scale

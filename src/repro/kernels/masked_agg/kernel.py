@@ -63,4 +63,5 @@ def masked_agg(global_tiled, deltas_tiled, weights_tiled, *,
         out_specs=pl.BlockSpec((rb, tile), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, tile), global_tiled.dtype),
         interpret=interpret,
+        name="masked_agg",
     )(global_tiled, deltas_tiled, weights_tiled[:, :, None])
