@@ -204,8 +204,7 @@ def vgg_federation(seed, size, **fl_kw):
 def compile_round(fed):
     """AOT-compile the round step at the signature ``fit`` calls it with
     (``fit`` then reuses the program); returns (compiled, seconds)."""
-    batches = jax.tree_util.tree_map(jnp.asarray,
-                                     fed.loader.round_batches(0))
+    batches = fed.server.place_batches(fed.loader.round_batches(0))
     weights = jnp.asarray(fed.loader.weights())
     t0 = time.perf_counter()
     compiled = fed.server.round_step.lower(

@@ -19,9 +19,15 @@ The round loop records (the engines, ``core/server.py``,
 
     fl.round          one per round or flush; ``round`` = its index
       fl.chunk        the cohort engine: one per chunk (attr ``chunk``)
-      fl.feed         batches for a round or a chunk
+      fl.feed         batches for a round or a chunk (a sync round's:
+                      attr ``staged``, true when the round before built
+                      and copied them, and its ``h2d`` child only
+                      reshapes them on the device)
         fl.feed.build   built on the host by the loader
         fl.feed.h2d     copied to the device
+      fl.feed.ahead   a sync round: the next round's batches, built and
+                      copied while this one computes (attr ``round``);
+                      children ``fl.feed.build`` and ``fl.feed.h2d``
       fl.dispatch     a call of a compiled round program (attr
                       ``program``: the CompileGuard's name)
       fl.sync         a device-to-host read (attr ``what``); each also

@@ -239,6 +239,14 @@ class Checkpointer(ServerHook):
         self._save(server)
 
 
+def _compact_shape(shape):
+    """``(clients, rows, cols)`` for a client batch leaf of more than
+    three dimensions (see ``Server.send_batches``); else its own."""
+    if len(shape) <= 3:
+        return shape
+    return (shape[0], int(np.prod(shape[1:-2])), shape[-2] * shape[-1])
+
+
 class Server:
     """``params`` is the topology *state*: the single global model for
     star topologies (hub, hierarchical), the stacked per-client replica
@@ -270,6 +278,9 @@ class Server:
         # survive the first round)
         self.params = jax.tree_util.tree_map(
             jnp.array, self.topology.init_state(params, fl))
+        # where a sync round's client batches go (``place_batches``):
+        # None puts them on the default device
+        self._batch_split = None
         if getattr(fl, "client_shards", 0):
             # the sharded round step commits its params output to the
             # (client,) mesh; committing the initial params the same way
@@ -277,11 +288,20 @@ class Server:
             # uncommitted->committed flip would otherwise retrace — and
             # trip the guard)
             from jax.sharding import NamedSharding, PartitionSpec
-            from ..launch.mesh import make_client_mesh
+            from ..launch.mesh import client_split, make_client_mesh
             self.params = jax.device_put(
                 self.params,
                 NamedSharding(make_client_mesh(fl.client_shards),
                               PartitionSpec()))
+            self._batch_split = client_split(fl.client_shards)
+
+        def unpack(arrays, shapes):
+            # a function of this server's own: its guard counts only the
+            # programs this server compiles
+            return [x.reshape(s) for x, s in zip(arrays, shapes)]
+        self._unpack = CompileGuard(unpack, name="unpack_batches",
+                                    max_programs=1, static_argnums=1,
+                                    out_shardings=self._batch_split)
         self.eval_fn = eval_fn
         self.key = jax.random.PRNGKey(seed)
         # the scored-selection engine (DESIGN.md §11): the server owns
@@ -361,14 +381,55 @@ class Server:
         self.hooks.append(hook)
         return self
 
+    def send_batches(self, client_batches):
+        """Enqueue the copy of a sync round's client batches to the
+        device; ``unpack_batches`` gives them back in their own shapes.
+
+        A leaf of more than three dimensions crosses as ``(clients,
+        rows, cols)``, its last two dimensions merged into ``cols``: a
+        TPU pads an array's minor dimension to 128 lanes, so a VGG16
+        image batch in its own shape (32 x 32 x 3) takes 4x its bytes
+        there, and a round copied ahead would hold that beside the
+        running round's; as ``(clients, rows, 96)`` it takes its own.
+        Under ``client_shards`` each device gets only its own clients'
+        rows (``launch.mesh.client_split``, the split the cohort stage
+        runs over), so the copies to the devices run side by side; else
+        everything goes to the default device."""
+        leaves, treedef = jax.tree_util.tree_flatten(client_batches)
+        shapes = tuple(tuple(x.shape) for x in leaves)
+        flat = [x.reshape(_compact_shape(s)) for x, s in zip(leaves, shapes)]
+        if self._batch_split is None:
+            flat = [jnp.asarray(x) for x in flat]
+        else:
+            flat = jax.device_put(flat, self._batch_split)
+        return treedef, shapes, flat
+
+    def unpack_batches(self, sent):
+        """``send_batches``' copy in its own shapes, where the round
+        program reads it (a reshape on the device).  Every round is
+        placed alike: the guarded round step holds one program, and a
+        committed placement is part of its signature."""
+        treedef, shapes, flat = sent
+        return jax.tree_util.tree_unflatten(treedef,
+                                            self._unpack(flat, shapes))
+
+    def place_batches(self, client_batches):
+        """A sync round's client batches on the device, in their own
+        shapes (``send_batches``, then ``unpack_batches``)."""
+        return self.unpack_batches(self.send_batches(client_batches))
+
     def run_round(self, client_batches, weights=None) -> RoundRecord:
         """client_batches: pytree with (C, steps, ...) leaves."""
-        return self._run_round(lambda: client_batches, weights)
+        return self._run_round(lambda: self.place_batches(client_batches),
+                               weights)
 
-    def _run_round(self, load: Callable[[], Any],
-                   weights=None) -> RoundRecord:
+    def _run_round(self, load: Callable[[], Any], weights=None,
+                   ahead: Optional[Callable[[int], None]] = None
+                   ) -> RoundRecord:
         """One round; ``load()`` gives its client batches (called first,
-        inside the round's span)."""
+        inside the round's span).  ``ahead(r + 1)``, if given, runs once
+        round ``r``'s program is dispatched and before its results are
+        read, so the host works while the device computes."""
         if self.async_engine is not None:
             raise RuntimeError(
                 "server is in buffered-async mode (FLConfig.async_buffer "
@@ -381,13 +442,13 @@ class Server:
                 "fleet/key bookkeeping — use run()/Federation.fit")
         r = len(self.history)
         with spans.round_span(r) as rspan:
-            rec = self._round(r, rspan, load(), weights)
+            rec = self._round(r, rspan, load(), weights, ahead)
         self.history.append(rec)
         self._trim_history()
         return rec
 
-    def _round(self, r: int, rspan, client_batches,
-               weights) -> RoundRecord:
+    def _round(self, r: int, rspan, client_batches, weights,
+               ahead: Optional[Callable[[int], None]]) -> RoundRecord:
         rk = self.next_key()
         c = self.fl.n_clients
         if weights is None:
@@ -433,6 +494,8 @@ class Server:
                     rk, **step_kw)
             if "codec_state" in metrics:
                 self.codec_state = metrics.pop("codec_state")
+            if ahead is not None:
+                ahead(r + 1)
             self.sel_history.append(spans.fetch(metrics["sel"], "sel"))
             ev = None
             if self.eval_fn is not None:
@@ -526,7 +589,12 @@ class Server:
         return self
 
     def run(self, rounds: int, batch_fn: Callable[[int], Any],
-            weights=None, log_every: int = 0) -> List[RoundRecord]:
+            weights=None, log_every: int = 0,
+            ahead: Optional[Callable[[int], None]] = None
+            ) -> List[RoundRecord]:
+        """``rounds`` rounds fed by ``batch_fn``.  ``ahead`` serves the
+        sync loop alone (see ``_run_round``); the async and cohort
+        engines feed themselves."""
         if self.async_engine is not None:
             # buffered-async mode: batch_fn is indexed by each client's
             # own dispatch window, not a shared round counter
@@ -545,7 +613,7 @@ class Server:
         self.hooks.extend(extra)
         try:
             for r in range(rounds):
-                self._run_round(lambda: batch_fn(r), weights)
+                self._run_round(lambda: batch_fn(r), weights, ahead)
         finally:
             for h in extra:
                 self.hooks.remove(h)

@@ -98,6 +98,13 @@ class Federation:
         self.fl = fl
         self.assign = assign
         self.loader = loader
+        # the sync engine's one-round-ahead feed (DESIGN.md §13): round
+        # r+1's batches, built and copied to the device while round r
+        # computes, as (loader, round, ``Server.send_batches``' copy);
+        # kept across fit calls, emptied by restore and run_round
+        self._staged = None
+        # sync rounds fed from that slot and rounds built in place
+        self.feed_counts = {"staged": 0, "built": 0}
         self.topology = resolve_topology(topology if topology is not None
                                          else fl.topology)
         round_step = build_round_step(loss_fn, assign, fl, loss_kwargs,
@@ -221,6 +228,12 @@ class Federation:
         one buffer flush, and the loader is indexed by each client's own
         dispatch window (the engine carries per-client counters across
         ``fit`` calls and restores), not a shared round counter.
+
+        A sync round feeds one round ahead: while round r runs on the
+        device, the host builds round r+1's batches and enqueues their
+        copy (``_stage``); round r+1 takes them from that slot
+        (``_take``).  A round's rows depend only on its index, so the
+        result is bitwise that of building each round in place.
         """
         if self.loader is None:
             raise ValueError("Federation has no data attached; pass "
@@ -236,11 +249,15 @@ class Federation:
                 rounds, lambda r, ids: self._feed(self.loader.client_batches,
                                                   r, ids),
                 weights=weights, log_every=log_every)
-        base = 0 if self.server.async_engine is not None \
-            else len(self.server.history)
-        return self.server.run(
-            rounds, lambda r: self._feed(self.loader.round_batches, base + r),
-            weights=weights, log_every=log_every)
+        if self.server.async_engine is not None:
+            # indexed by each client's dispatch window: nothing to stage
+            return self.server.run(
+                rounds, lambda r: self._feed(self.loader.round_batches, r),
+                weights=weights, log_every=log_every)
+        base = len(self.server.history)
+        return self.server.run(rounds, lambda r: self._take(base + r),
+                               weights=weights, log_every=log_every,
+                               ahead=self._stage)
 
     @staticmethod
     def _feed(build: Callable, *args) -> PyTree:
@@ -251,7 +268,43 @@ class Federation:
             with spans.span("fl.feed.h2d"):
                 return jax.tree_util.tree_map(jnp.asarray, host)
 
+    def _take(self, rnd: int) -> PyTree:
+        """Sync round ``rnd``'s batches on the device: from the slot
+        where the round before staged them, else built in place (the
+        first round, or after ``run_round``, ``restore`` or a new
+        loader)."""
+        slot, self._staged = self._staged, None
+        staged = slot is not None and slot[0] is self.loader \
+            and slot[1] == rnd
+        self.feed_counts["staged" if staged else "built"] += 1
+        with spans.span("fl.feed", staged=staged):
+            if not staged:
+                return self._build(rnd, self.server.place_batches)
+            # the children hand over what round rnd - 1 built and
+            # copied, reshaped on the device once that round is done
+            with spans.span("fl.feed.build"):
+                pass
+            with spans.span("fl.feed.h2d"):
+                return self.server.unpack_batches(slot[2])
+
+    def _build(self, rnd: int, place: Callable) -> Any:
+        """Sync round ``rnd``'s batches, built on the host and handed to
+        ``place`` (a ``Server`` method that enqueues their copy)."""
+        with spans.span("fl.feed.build"):
+            host = self.loader.round_batches(rnd)
+        with spans.span("fl.feed.h2d"):
+            return place(host)
+
+    def _stage(self, rnd: int) -> None:
+        """Build round ``rnd``'s batches and enqueue their copy while
+        round ``rnd - 1`` runs on the device (``Server._round`` calls
+        this between the dispatch and the first read of its results)."""
+        with spans.span("fl.feed.ahead", round=rnd):
+            self._staged = (self.loader, rnd,
+                            self._build(rnd, self.server.send_batches))
+
     def run_round(self, client_batches, weights=None) -> RoundRecord:
+        self._staged = None
         return self.server.run_round(client_batches, weights)
 
     def evaluate(self) -> Optional[float]:
@@ -284,4 +337,5 @@ class Federation:
 
     def restore(self, path: str) -> Dict:
         from ..ckpt import restore_server_state
+        self._staged = None
         return restore_server_state(path, self.server)

@@ -18,12 +18,12 @@ subgroups (their reduce stays on local interconnect — the edge
 aggregator); only the per-edge partial aggregates cross the ``edge``
 axis boundary, which is the edge->hub WAN link.
 
-``make_client_mesh`` / ``shard_over_clients`` are the cohort engine's
-1-D ``(client,)`` device mesh (DESIGN.md §13): the in-flight cohort's
-leading client axis is split over device groups with ``shard_map``, each
-group vmapping its shard of clients — per-client rows of a batched
-local update are independent of their cohort, so the sharded run is
-bitwise-equal to the single-device vmap (property-tested).
+``make_client_mesh`` / ``client_split`` / ``shard_over_clients`` are the
+cohort engine's 1-D ``(client,)`` device mesh (DESIGN.md §13): the
+in-flight cohort's leading client axis is split over device groups with
+``shard_map``, each group vmapping its shard of clients — per-client
+rows of a batched local update are independent of their cohort, so the
+sharded run is bitwise-equal to the single-device vmap (property-tested).
 
 Functions, not module constants: importing this module never touches
 jax device state (dryrun.py must set XLA_FLAGS before first jax init).
@@ -118,6 +118,15 @@ def make_client_mesh(n_shards: int):
                          devices=jax.devices()[:n_shards])
 
 
+def client_split(n_shards: int):
+    """How a per-client operand lies on the ``(client,)`` mesh: its
+    leading client axis split into ``n_shards`` device-local blocks.
+    ``shard_over_clients`` splits the cohort stage's operands so, and
+    the sync feed puts a round's batches there before the round runs."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return NamedSharding(make_client_mesh(n_shards), P("client"))
+
+
 def shard_over_clients(fn, n_shards: int, n_clients: int):
     """Split ``fn``'s leading client axis over a ``(client,)`` mesh.
 
@@ -137,14 +146,14 @@ def shard_over_clients(fn, n_shards: int, n_clients: int):
             f"client_shards={n_shards} must divide the cohort of "
             f"{n_clients} clients ({ndev} devices visible); valid "
             f"shard counts here: {valid}")
-    mesh = make_client_mesh(n_shards)
+    split = client_split(n_shards)
     from jax.sharding import PartitionSpec as P
 
     def wrapped(replicated, *per_client):
         sharded = jax.shard_map(
-            fn, mesh=mesh,
-            in_specs=(P(),) + tuple(P("client") for _ in per_client),
-            out_specs=P("client"), check_vma=False)
+            fn, mesh=split.mesh,
+            in_specs=(P(),) + tuple(split.spec for _ in per_client),
+            out_specs=split.spec, check_vma=False)
         return sharded(replicated, *per_client)
 
     return wrapped
